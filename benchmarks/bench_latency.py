@@ -4,7 +4,6 @@ Components (paper): internal transmission (PCB, sub-us), external
 transmission (optical, 1-3us), inference (FENIX 1.2us FPGA vs FlowLens
 >1000us CPU).  We report:
   - the FPGA cycle-model latency of our INT8 models (ZU19EG-like array)
-  - the TPU-v5e roofline latency of the same window batch (Pallas kernel)
   - measured CPU wall-time per inference (this container, for reference),
     for BOTH the served INT8 integer path (kernels/int8_matmul, "ref"
     backend) and the float parent model — the int8-vs-float serving
@@ -25,8 +24,7 @@ import numpy as np
 from benchmarks._io import write_json_atomic
 from repro.configs.fenix_models import fenix_cnn, fenix_rnn
 from repro.core.model_engine.inference import (CycleModel, EngineModel,
-                                               macs_per_inference,
-                                               tpu_latency_us)
+                                               macs_per_inference)
 from repro.data.synthetic_traffic import make_flows, windows_from_flows
 from repro.models import traffic
 from repro.quant.quantize import quantize_traffic
@@ -86,7 +84,6 @@ def main(out_path: str = None) -> Dict:
                 e: cm.farm_batch_latency_us(cfg, 128, e)
                 for e in (1, 2, 4)},
             "farm4_fused_cpu_us_per_inf": fused_us,
-            "tpu_roofline": tpu_latency_us(cfg, batch=128),
             "cpu_measured_us_per_inf": cpu_us,
             "float_cpu_us_per_inf": float_us,
             "int8_vs_float_cpu_ratio": cpu_us / max(float_us, 1e-9),

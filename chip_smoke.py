@@ -77,29 +77,6 @@ def require_tpu(count: int):
     return devs
 
 
-class CompileLog:
-    """Backend-compile seconds and persistent-cache hits/misses, read from
-    JAX's monitoring events."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.events = {"hits": 0, "misses": 0}
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _dur(self, name, secs, **_):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-    def _event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.events["hits"] += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.events["misses"] += 1
-
-
 # -- phase 2: kernel parity ---------------------------------------------------
 
 def _gate_case(seed: int, n: int, random_lut: bool, cost: int):
@@ -257,7 +234,7 @@ def lowered_kernels(sys_) -> dict:
             "gemm": text.count(GEMM_NAME)}
 
 
-def replay(spec, clog: CompileLog, engine_kw=None, **cfg_kw):
+def replay(spec, clog, engine_kw=None, **cfg_kw):
     """One FenixSystem replay; returns (system, verdicts, seconds)."""
     from repro.core.data_engine.state import EngineConfig
     from repro.core.fenix import FenixConfig, FenixSystem
@@ -277,7 +254,7 @@ def replay(spec, clog: CompileLog, engine_kw=None, **cfg_kw):
     return sys_, verdict, wall
 
 
-def gate_at_knee(spec, span_us: int, clog: CompileLog) -> None:
+def gate_at_knee(spec, span_us: int, clog) -> None:
     """The compiled gate inside the scan where it binds: on-core PRNG vs
     supplied draws at ``KNEE_HZ``, grant fraction and bucket-limited
     drops (the grants that lifting the bucket cap adds)."""
@@ -306,7 +283,7 @@ def gate_at_knee(spec, span_us: int, clog: CompileLog) -> None:
     assert abs(drops["prng"] - drops["ref"]) < GRANT_TOL, drops
 
 
-def main_path(clog: CompileLog) -> None:
+def main_path(clog) -> None:
     from repro.data.trace_ingest import TraceSpec
 
     with tempfile.TemporaryDirectory() as workdir:
@@ -351,7 +328,7 @@ def main_path(clog: CompileLog) -> None:
 
 # -- --four-chips: the farm on a 2x2 mesh ------------------------------------
 
-def four_chips(clog: CompileLog) -> None:
+def four_chips(clog) -> None:
     import jax
     from repro.core.data_engine.state import EngineConfig
     from repro.core.fenix import FenixConfig, FenixSystem
@@ -404,10 +381,10 @@ def main() -> None:
         sys.exit(f"chip_smoke: no src/repro next to {__file__}")
     sys.path.insert(0, SRC)
     devs = require_tpu(4 if args.four_chips else 1)
-    from repro import compile_cache
+    from repro import compile_cache, tracing
 
     cache_dir = compile_cache.enable()
-    clog = CompileLog()
+    clog = tracing.compile_log()
     log(f"device: {devs[0].platform} {devs[0].device_kind} x{len(devs)}; "
         f"compile cache {cache_dir}")
     if args.four_chips:
@@ -416,8 +393,9 @@ def main() -> None:
         gate_parity()
         gemm_parity()
         main_path(clog)
-    log(f"backend compile total {clog.seconds:.2f} s; persistent cache "
-        f"{clog.events}")
+    log(f"backend compile total {clog.seconds:.2f} s in {clog.compiles} "
+        f"compiles; persistent cache {clog.hits} hits, {clog.misses} "
+        f"misses")
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}))

@@ -29,6 +29,7 @@ from repro.core.data_engine import buffer_manager as bm
 from repro.core.data_engine import flow_tracker as ft
 from repro.core.data_engine import rate_limiter as rl
 from repro.core.data_engine.state import EngineConfig
+from repro.tracing import scope
 
 I32 = jnp.int32
 
@@ -86,61 +87,70 @@ def process_batch_fast(state: Dict, packets: Dict, cfg: EngineConfig
 
     n = packets["ts_us"].shape[0]
     ts = packets["ts_us"].astype(I32)
-    h = hash_five_tuple(packets["src_ip"], packets["dst_ip"],
-                        packets["src_port"], packets["dst_port"],
-                        packets["proto"])
-    slot = (h & jnp.uint32(cfg.n_slots - 1)).astype(I32)
-    stored = state["hash"][slot]
-    # first occurrence of each slot in this batch determines new/collision
-    first_in_batch = _first_occurrence(slot, cfg.n_slots)
-    is_new = first_in_batch & ((stored == 0) | (stored != h))
+    with scope("flow_table"):
+        h = hash_five_tuple(packets["src_ip"], packets["dst_ip"],
+                            packets["src_port"], packets["dst_port"],
+                            packets["proto"])
+        slot = (h & jnp.uint32(cfg.n_slots - 1)).astype(I32)
+        stored = state["hash"][slot]
+        # first occurrence of each slot in this batch determines
+        # new/collision
+        first_in_batch = _first_occurrence(slot, cfg.n_slots)
+        is_new = first_in_batch & ((stored == 0) | (stored != h))
     # probability lookup against batch-start backlog (approximation)
-    run = (_running_count_dense(slot, n) if cfg.dense_backlog
-           else _running_count(slot, n))
-    t_i = jnp.maximum(ts - state["bklog_t"][slot], 0)
-    c_i = jnp.maximum(state["bklog_n"][slot], 0) + run
-    key, sub = jax.random.split(state["rng_key"])
+    with scope("sort_segment"):
+        run = (_running_count_dense(slot, n) if cfg.dense_backlog
+               else _running_count(slot, n))
+    with scope("flow_table"):
+        t_i = jnp.maximum(ts - state["bklog_t"][slot], 0)
+        c_i = jnp.maximum(state["bklog_n"][slot], 0) + run
     # fused admission: LUT lookup + threshold + token bucket in ONE call
     # (rl.admit_batch -> fused_admission).  Bucket semantics: spend_i <=
     # burst credit (capped at batch start) + refill_i.  The cap limits
     # *idle accumulation*, not throughput: refill earned during the batch
     # is spendable immediately (matches the scan semantics whenever packet
     # timestamps are spread out; see test_data_engine).
-    granted, bucket_new = rl.admit_batch(state, cfg, t_i, c_i, ts, sub)
-    state = dict(state)
-    state["rng_key"] = key
-    state["bucket"] = bucket_new
-    state["t_last"] = ts[-1]
-    state["granted"] = state["granted"] + granted.sum().astype(I32)
+    with scope("gate"):
+        key, sub = jax.random.split(state["rng_key"])
+        granted, bucket_new = rl.admit_batch(state, cfg, t_i, c_i, ts, sub)
+        state = dict(state)
+        state["rng_key"] = key
+        state["bucket"] = bucket_new
+        state["t_last"] = ts[-1]
+        state["granted"] = state["granted"] + granted.sum().astype(I32)
     # features + mirror payloads from the PRE-update ring (F1..F8 then F9);
     # ipd is 0 for flows new to the table (no previous timestamp)
-    known = (stored != 0) & (stored == h)
-    feat = jnp.stack(
-        [packets["pkt_len"].astype(I32),
-         jnp.where(known, jnp.maximum(ts - state["last_ts"][slot], 0), 0)],
-        axis=-1)
-    idx = state["buff_idx"][slot]
-    order = jnp.mod(idx[:, None] + jnp.arange(cfg.ring_depth)[None],
-                    cfg.ring_depth)
-    seq = jnp.take_along_axis(state["ring"][slot], order[..., None], axis=1)
-    payload = jnp.concatenate([seq, feat[:, None]], axis=1)
+    with scope("feature_ring"):
+        known = (stored != 0) & (stored == h)
+        feat = jnp.stack(
+            [packets["pkt_len"].astype(I32),
+             jnp.where(known, jnp.maximum(ts - state["last_ts"][slot], 0),
+                       0)],
+            axis=-1)
+        idx = state["buff_idx"][slot]
+        order = jnp.mod(idx[:, None] + jnp.arange(cfg.ring_depth)[None],
+                        cfg.ring_depth)
+        seq = jnp.take_along_axis(state["ring"][slot], order[..., None],
+                                  axis=1)
+        payload = jnp.concatenate([seq, feat[:, None]], axis=1)
+        # ring bulk update (last write per slot wins)
+        state["ring"] = state["ring"].at[slot, idx].set(feat)
+        nxt = jnp.where(idx + 1 == cfg.ring_depth, 0, idx + 1)
+        state["buff_idx"] = state["buff_idx"].at[slot].set(nxt)
     # flow table bulk update (last write per slot wins)
-    state["hash"] = state["hash"].at[slot].set(h)
-    state["ring"] = state["ring"].at[slot, idx].set(feat)
-    nxt = jnp.where(idx + 1 == cfg.ring_depth, 0, idx + 1)
-    state["buff_idx"] = state["buff_idx"].at[slot].set(nxt)
-    state["last_ts"] = state["last_ts"].at[slot].set(ts)
-    state["bklog_n"] = state["bklog_n"].at[slot].add(1)
-    state["bklog_n"] = state["bklog_n"].at[slot].set(
-        jnp.where(granted, 0, state["bklog_n"][slot]))
-    state["bklog_t"] = state["bklog_t"].at[slot].set(
-        jnp.where(granted, ts, state["bklog_t"][slot]))
-    state["flow_cnt"] = state["flow_cnt"] + is_new.sum().astype(I32)
-    state["win_pkt_cnt"] = state["win_pkt_cnt"] + n
+    with scope("flow_table"):
+        state["hash"] = state["hash"].at[slot].set(h)
+        state["last_ts"] = state["last_ts"].at[slot].set(ts)
+        state["bklog_n"] = state["bklog_n"].at[slot].add(1)
+        state["bklog_n"] = state["bklog_n"].at[slot].set(
+            jnp.where(granted, 0, state["bklog_n"][slot]))
+        state["bklog_t"] = state["bklog_t"].at[slot].set(
+            jnp.where(granted, ts, state["bklog_t"][slot]))
+        state["flow_cnt"] = state["flow_cnt"] + is_new.sum().astype(I32)
+        state["win_pkt_cnt"] = state["win_pkt_cnt"] + n
+        verdict = jnp.where(state["cls"][slot] >= 0, state["cls"][slot], -1)
     out = {"granted": granted, "slot": slot, "hash": h, "payload": payload,
-           "verdict": jnp.where(state["cls"][slot] >= 0,
-                                state["cls"][slot], -1),
-           "is_new": is_new}
+           "verdict": verdict, "is_new": is_new}
     return state, out
 
 

@@ -88,6 +88,8 @@ from repro.core.model_engine.inference import EngineModel
 from repro.core.data_engine import flow_tracker as ft
 from repro.data.trace_ingest import TraceSpec
 from repro.kernels.backend import resolve
+from repro import tracing
+from repro.tracing import scope
 
 I32 = jnp.int32
 
@@ -258,12 +260,16 @@ def _make_pipe_local(lcfg: EngineConfig, iocfg: vio.IOConfig, tree,
     def de_local(state, queues, dline, chunk):
         ts = chunk["ts_us"].astype(I32)
         now = ts[-1]
-        state, dline = dl.deliver(state, dline, now, lcfg.n_slots)
+        with scope("deliver"):
+            state, dline = dl.deliver(state, dline, now, lcfg.n_slots)
         batch = {k: chunk[k] for k in PKT_KEYS}
         state, out = de.process_batch_fast(state, batch, lcfg)
         payload = chunk.get("payload", out["payload"])
-        queues = vio.enqueue_device(queues, iocfg, out["granted"],
-                                    out["slot"], out["hash"], payload)
+        with scope("vio_enqueue"):
+            queues = vio.enqueue_device(queues, iocfg, out["granted"],
+                                        out["slot"], out["hash"], payload)
+            # FIFO occupancy after enqueue: the high-water mark's sample
+            occupancy = queues["tail"] - queues["head"]
         verdict = out["verdict"]
         n_tree = jnp.asarray(0, I32)
         if tree is not None:
@@ -277,7 +283,7 @@ def _make_pipe_local(lcfg: EngineConfig, iocfg: vio.IOConfig, tree,
         aux = {"verdict": verdict, "now": now, "ts_first": ts[0],
                "granted": out["granted"].sum().astype(I32),
                "classified": jnp.sum((verdict >= 0).astype(I32)),
-               "n_tree": n_tree}
+               "n_tree": n_tree, "fifo_depth": occupancy}
         return state, queues, dline, aux
 
     return de_local
@@ -306,19 +312,25 @@ def _make_single_step(ecfg: EngineConfig, iocfg: vio.IOConfig,
     def step_fn(carry, chunk):
         state, queues, dline = carry
         state, queues, dline, aux = de_local(state, queues, dline, chunk)
-        budget = vio.step_budget(aux["ts_first"], aux["now"],
-                                 ecfg.token_rate_per_us, iocfg.queue_len)
-        queues, s2, h2, f2, cnt = vio.dequeue_device(queues, iocfg,
-                                                     budget)
-        cls = model.infer(f2)
-        dline = dl.push(dline, aux["now"] + loop_latency_us, s2, h2, cls,
-                        cnt)
-        state = jax.lax.cond(
-            chunk["_cp"], lambda s: rl.control_plane_update(s, ecfg),
-            lambda s: s, state)
+        with scope("vio_dequeue"):
+            budget = vio.step_budget(aux["ts_first"], aux["now"],
+                                     ecfg.token_rate_per_us,
+                                     iocfg.queue_len)
+            queues, s2, h2, f2, cnt = vio.dequeue_device(queues, iocfg,
+                                                         budget)
+        with scope("infer"):
+            cls = model.infer(f2)
+        with scope("delay_push"):
+            dline = dl.push(dline, aux["now"] + loop_latency_us, s2, h2,
+                            cls, cnt)
+        with scope("control_plane"):
+            state = jax.lax.cond(
+                chunk["_cp"], lambda s: rl.control_plane_update(s, ecfg),
+                lambda s: s, state)
         stats = jnp.stack([aux["granted"], cnt, aux["classified"],
                            aux["n_tree"]])
-        return (state, queues, dline), (aux["verdict"], stats)
+        return (state, queues, dline), (aux["verdict"], stats,
+                                        aux["fifo_depth"])
 
     return step_fn
 
@@ -378,15 +390,20 @@ def _make_pipes_step(cfg: "FenixConfig", lcfg: EngineConfig, model, tree,
                                  num_pipes * iocfg.queue_len)
         share = vio.pipe_shares(gath[:, 0],
                                 budget)[jax.lax.axis_index("pipe")]
-        queues, s2, h2, f2, cnt = vio.dequeue_device(queues, iocfg, share)
-        cls = model.infer(f2)
-        dline = dl.push(dline, aux["now"] + cfg.loop_latency_us, s2, h2,
-                        cls, cnt)
+        with scope("vio_dequeue"):
+            queues, s2, h2, f2, cnt = vio.dequeue_device(queues, iocfg,
+                                                         share)
+        with scope("infer"):
+            cls = model.infer(f2)
+        with scope("delay_push"):
+            dline = dl.push(dline, aux["now"] + cfg.loop_latency_us, s2,
+                            h2, cls, cnt)
         # in-scan control plane at T_w boundaries (applies to frozen pipes
         # too — the host oracle rolled every pipe's window, active or not)
-        state = jax.lax.cond(
-            chunk["_cp"], lambda s: rl.control_plane_update(s, lcfg),
-            lambda s: s, state)
+        with scope("control_plane"):
+            state = jax.lax.cond(
+                chunk["_cp"], lambda s: rl.control_plane_update(s, lcfg),
+                lambda s: s, state)
         stats = jnp.stack([aux["granted"], cnt, aux["classified"],
                            aux["n_tree"]])
         if masked:
@@ -783,9 +800,30 @@ class FenixSystem:
         ``adapter=``, ``trace_labels=``, ``limit=``, ``labels_by_flow=``)
         are deprecated spellings of the same thing and map onto
         ``trace=``.
+
+        Every call is one ``fenix.run_trace`` span (:mod:`repro.tracing`)
+        whose attributes are the call's deltas of ``stats`` (``packets``,
+        ``granted``, ``served``, ``steps``, ``fifo_drops``,
+        ``inflight_drops``), the Model-Engine ``lanes`` run, the FIFO's
+        ``fifo_depth_max`` (device drivers; None elsewhere) and the
+        backend ``compiles`` it caused.
         """
         trace = self._resolve_trace(trace, stream, labels_by_flow, source,
                                     adapter, trace_labels, limit)
+        clog = tracing.compile_log()
+        before, compiles = self._counts(), clog.compiles
+        self._fifo_depth_max = None
+        with tracing.span("fenix.run_trace") as attrs:
+            out = self._run_trace_driver(trace)
+            after = self._counts()
+            attrs.update({k: after[k] - before[k] for k in after})
+            attrs["lanes"] = (attrs["served"] if self.cfg.driver == "host"
+                              else attrs["steps"] * self._lanes_per_step)
+            attrs["fifo_depth_max"] = self._fifo_depth_max
+            attrs["compiles"] = clog.compiles - compiles
+        return out
+
+    def _run_trace_driver(self, trace) -> Dict[str, np.ndarray]:
         if isinstance(trace, TraceSpec) and self.cfg.driver == "device" \
                 and self.oracle is None:
             return self._run_trace_device_stream(trace)
@@ -795,6 +833,26 @@ class FenixSystem:
         if self.cfg.driver == "host":
             return self._run_trace_host(stream)
         return self._run_trace_device(stream)
+
+    def _counts(self) -> Dict[str, int]:
+        """The cumulative ``stats`` a ``fenix.run_trace`` span carries the
+        deltas of."""
+        st = self.stats
+        return {"packets": st["packets"], "granted": st["granted"],
+                "served": st["inferences"],
+                "steps": sum(st["engine_q_depth_hist"][0]),
+                "fifo_drops": st["dropped_q"],
+                "inflight_drops": st["dropped_inflight"]}
+
+    @property
+    def _lanes_per_step(self) -> int:
+        """Lanes the Model Engine runs inference on per scan step (every
+        pipe's dequeue, or every engine's ingress on the farm)."""
+        io = self.cfg.io
+        if self._use_farm:
+            return self.cfg.num_engines * vio.engine_serve_lanes(
+                io, self.cfg.num_pipes)
+        return self.cfg.num_pipes * io.serve_lanes
 
     def _resolve_trace(self, trace, stream, labels_by_flow, source,
                        adapter, trace_labels, limit):
@@ -827,7 +885,12 @@ class FenixSystem:
                          limit=limit)
 
     def _accum_device_stats(self, n: int, n_batches: int,
-                            stat_sum: np.ndarray) -> None:
+                            stat_sum: np.ndarray,
+                            depths: List[jax.Array]) -> None:
+        # the FIFO's occupancy after each step's enqueue, copied to the
+        # host since dispatch (its read stays off the scan's wait)
+        self._fifo_depth_max = max((int(np.max(np.asarray(d)))
+                                    for d in depths), default=None)
         self.stats["packets"] += n
         self.stats["granted"] += int(stat_sum[0])
         self.stats["inferences"] += int(stat_sum[1])
@@ -847,42 +910,57 @@ class FenixSystem:
         cfg = self.cfg
         n = len(stream["ts_us"])
         B, cpe = cfg.batch_size, cfg.control_plane_every
-        arrs = {k: jnp.asarray(stream[k]) for k in PKT_KEYS}
-        if self.oracle is not None and "flow_idx" in stream:
-            from repro.data.synthetic_traffic import oracle_payloads
-            pay = oracle_payloads(self.oracle, stream["flow_idx"],
-                                  stream["flow_pos"], cfg.io.feat_len)
-            arrs["payload"] = jnp.asarray(pay)
-        self._sync_inflight_to_device()
-        self._ensure_jits()
         n_chunks = n // B
-        chunked = {k: v[:n_chunks * B].reshape((n_chunks, B)
-                                               + v.shape[1:])
-                   for k, v in arrs.items()}
-        chunked["_cp"] = jnp.asarray(
-            (np.arange(1, n_chunks + 1) % cpe) == 0)
-        tail = ({k: v[n_chunks * B:] for k, v in arrs.items()}
-                if n_chunks * B < n else None)
+        with tracing.span("fenix.stage_in"):
+            arrs = {k: jnp.asarray(stream[k]) for k in PKT_KEYS}
+            if self.oracle is not None and "flow_idx" in stream:
+                from repro.data.synthetic_traffic import oracle_payloads
+                pay = oracle_payloads(self.oracle, stream["flow_idx"],
+                                      stream["flow_pos"], cfg.io.feat_len)
+                arrs["payload"] = jnp.asarray(pay)
+            self._sync_inflight_to_device()
+            self._ensure_jits()
+            chunked = {k: v[:n_chunks * B].reshape((n_chunks, B)
+                                                   + v.shape[1:])
+                       for k, v in arrs.items()}
+            chunked["_cp"] = jnp.asarray(
+                (np.arange(1, n_chunks + 1) % cpe) == 0)
+            tail = ({k: v[n_chunks * B:] for k, v in arrs.items()}
+                    if n_chunks * B < n else None)
         carry = (self.state, self.queues, self._dl)
         verd_parts: List[jax.Array] = []
+        depths: List[jax.Array] = []
         stat_sum = np.zeros(4, np.int64)
         if n_chunks:
-            carry, (vd, st) = self._scan_jit(carry, chunked)
-            verd_parts.append(vd.reshape(-1))
-            stat_sum += np.asarray(st).astype(np.int64).sum(axis=0)
+            with tracing.span("fenix.dispatch"):
+                tracing.note_program("scan", self._scan_jit, n_chunks,
+                                     carry, chunked)
+                carry, (vd, st, depth) = self._scan_jit(carry, chunked)
+                depth.copy_to_host_async()
+                depths.append(depth)
+                verd_parts.append(vd.reshape(-1))
+            with tracing.span("fenix.wait"):
+                stat_sum += np.asarray(st).astype(np.int64).sum(axis=0)
         n_batches = n_chunks
         if tail is not None:
             n_batches += 1
             tail["_cp"] = jnp.asarray(n_batches % cpe == 0)
-            carry, (vd, st) = self._step_jit(carry, tail)
-            verd_parts.append(vd)
-            stat_sum += np.asarray(st).astype(np.int64)
+            with tracing.span("fenix.dispatch"):
+                tracing.note_program("step", self._step_jit,
+                                     n - n_chunks * B, carry, tail)
+                carry, (vd, st, depth) = self._step_jit(carry, tail)
+                depth.copy_to_host_async()
+                depths.append(depth)
+                verd_parts.append(vd)
+            with tracing.span("fenix.wait"):
+                stat_sum += np.asarray(st).astype(np.int64)
         self.state, self.queues, self._dl = carry
         self._dl_dirty = True
-        self._accum_device_stats(n, n_batches, stat_sum)
-        verdicts = (np.concatenate([np.asarray(v) for v in verd_parts])
-                    .astype(np.int32) if verd_parts
-                    else np.full(n, -1, np.int32))
+        with tracing.span("fenix.fetch_out"):
+            self._accum_device_stats(n, n_batches, stat_sum, depths)
+            verdicts = (np.concatenate([np.asarray(v) for v in verd_parts])
+                        .astype(np.int32) if verd_parts
+                        else np.full(n, -1, np.int32))
         return {"verdict": verdicts}
 
     # chunks staged per streaming block: control_plane_every scan steps x
@@ -899,10 +977,12 @@ class FenixSystem:
         between scans).  The in-scan ``"_cp"`` control plane carries over
         unchanged — still zero host syncs, and the donated carry lets
         consecutive blocks reuse the same state buffers."""
-        self._sync_inflight_to_device()
-        self._ensure_jits()
+        with tracing.span("fenix.stage_in"):
+            self._sync_inflight_to_device()
+            self._ensure_jits()
         carry = (self.state, self.queues, self._dl)
         verd_parts: List[jax.Array] = []
+        depths: List[jax.Array] = []
         stat_sum = np.zeros(4, np.int64)
         n = 0
         n_batches = 0
@@ -910,31 +990,43 @@ class FenixSystem:
         for kind, block in self._staged_blocks(spec):
             if kind == "block":
                 steps = block["_cp"].shape[0]
-                carry, (vd, st) = self._scan_jit(carry, block)
-                verd_parts.append(vd.reshape(-1))
-                stat_sum += np.asarray(st).astype(np.int64).sum(axis=0)
+                with tracing.span("fenix.dispatch"):
+                    tracing.note_program("scan", self._scan_jit, steps,
+                                         carry, block)
+                    carry, (vd, st, depth) = self._scan_jit(carry, block)
+                    verd_parts.append(vd.reshape(-1))
                 n += steps * B
                 n_batches += steps
             else:                                   # trailing < B packets
                 n += int(block["ts_us"].shape[0])
                 n_batches += 1
-                carry, (vd, st) = self._step_jit(carry, block)
-                verd_parts.append(vd)
-                stat_sum += np.asarray(st).astype(np.int64)
+                with tracing.span("fenix.dispatch"):
+                    tracing.note_program("step", self._step_jit,
+                                         int(block["ts_us"].shape[0]),
+                                         carry, block)
+                    carry, (vd, st, depth) = self._step_jit(carry, block)
+                    verd_parts.append(vd)
+            depth.copy_to_host_async()
+            depths.append(depth)
+            with tracing.span("fenix.wait"):
+                stat_sum += np.asarray(st).astype(np.int64).reshape(
+                    -1, 4).sum(axis=0)
         self.state, self.queues, self._dl = carry
         self._dl_dirty = True
-        self._accum_device_stats(n, n_batches, stat_sum)
-        verdicts = (np.concatenate([np.asarray(v) for v in verd_parts])
-                    .astype(np.int32) if verd_parts
-                    else np.full(0, -1, np.int32))
+        with tracing.span("fenix.fetch_out"):
+            self._accum_device_stats(n, n_batches, stat_sum, depths)
+            verdicts = (np.concatenate([np.asarray(v) for v in verd_parts])
+                        .astype(np.int32) if verd_parts
+                        else np.full(0, -1, np.int32))
         return {"verdict": verdicts}
 
-    def _stage_gen(self, spec: TraceSpec):
+    def _stage_gen(self, spec: TraceSpec, parent: tracing.Parent):
         """Parse the capture chunk-wise and re-batch it into staged
         ("block", {[W, B] columns + "_cp" [W]}) items plus one final
         ("tail", {[<B] columns + scalar "_cp"}).  Each item is already on
         device (``jax.device_put``) when yielded — this is the half the
-        ingest thread overlaps with the scans."""
+        ingest thread overlaps with the scans.  Its ``fenix.parse`` and
+        ``fenix.device_put`` spans belong to ``parent``'s call."""
         B, cpe = self.cfg.batch_size, self.cfg.control_plane_every
         W = cpe * self._STAGE_WINDOWS
         pend = {k: [] for k in PKT_KEYS}
@@ -943,18 +1035,26 @@ class FenixSystem:
 
         def emit(cols, steps):
             nonlocal chunk_i
-            block = {k: jax.device_put(
-                np.ascontiguousarray(cols[k][:steps * B])
-                .reshape(steps, B)) for k in PKT_KEYS}
-            block["_cp"] = jax.device_put(
-                (np.arange(chunk_i + 1, chunk_i + steps + 1) % cpe) == 0)
+            with tracing.span("fenix.device_put", parent):
+                block = {k: jax.device_put(
+                    np.ascontiguousarray(cols[k][:steps * B])
+                    .reshape(steps, B)) for k in PKT_KEYS}
+                block["_cp"] = jax.device_put(
+                    (np.arange(chunk_i + 1, chunk_i + steps + 1) % cpe)
+                    == 0)
             chunk_i += steps
             return "block", block
 
-        for raw in spec.iter_chunks():
-            for k in PKT_KEYS:
-                pend[k].append(np.asarray(raw[k]))
-            pend_n += len(raw["ts_us"])
+        chunks = spec.iter_chunks()
+        while True:
+            with tracing.span("fenix.parse", parent):
+                raw = next(chunks, None)
+                if raw is not None:
+                    for k in PKT_KEYS:
+                        pend[k].append(np.asarray(raw[k]))
+                    pend_n += len(raw["ts_us"])
+            if raw is None:
+                break
             while pend_n >= W * B:
                 cols = {k: np.concatenate(pend[k]) for k in PKT_KEYS}
                 yield emit(cols, W)
@@ -966,17 +1066,18 @@ class FenixSystem:
             if steps:
                 yield emit(cols, steps)
             if pend_n > steps * B:
-                tail = {k: jax.device_put(cols[k][steps * B:])
-                        for k in PKT_KEYS}
-                tail["_cp"] = jax.device_put(
-                    np.bool_((chunk_i + 1) % cpe == 0))
+                with tracing.span("fenix.device_put", parent):
+                    tail = {k: jax.device_put(cols[k][steps * B:])
+                            for k in PKT_KEYS}
+                    tail["_cp"] = jax.device_put(
+                        np.bool_((chunk_i + 1) % cpe == 0))
                 yield "tail", tail
 
     def _staged_blocks(self, spec: TraceSpec):
         """Yield `_stage_gen` items, double-buffered through a bounded
         queue when ``spec.overlap`` — the producer thread parses and
         stages block k+1 while the caller scans block k."""
-        gen = self._stage_gen(spec)
+        gen = self._stage_gen(spec, tracing.current())
         if not spec.overlap:
             yield from gen
             return
@@ -1010,7 +1111,8 @@ class FenixSystem:
         t.start()
         try:
             while True:
-                item = q.get()
+                with tracing.span("fenix.ingest_wait"):
+                    item = q.get()
                 if item is None:
                     break
                 yield item
@@ -1203,7 +1305,8 @@ class FenixSystem:
                                                                 batch)
                 served_sum += np.asarray(assign).astype(np.int64)
             else:
-                carry_p, (vd, st) = self._pipe_tail_jit(carry_p, batch)
+                carry_p, (vd, st, _) = self._pipe_tail_jit(carry_p,
+                                                           batch)
             self.pstate, self.pqueues, self.pdl = jax.tree.map(
                 lambda full, part: full.at[p].set(part),
                 (self.pstate, self.pqueues, self.pdl), carry_p)

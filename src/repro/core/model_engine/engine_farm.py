@@ -48,6 +48,7 @@ from jax.sharding import Mesh, PartitionSpec
 from repro.core.data_engine import rate_limiter as rl
 from repro.core.model_engine import delay_line as dl
 from repro.core.model_engine import vector_io as vio
+from repro.tracing import scope
 
 I32 = jnp.int32
 
@@ -217,59 +218,67 @@ def make_farm_step(num_pipes: int, num_engines: int, iocfg: vio.IOConfig,
         # cell derives them from the gathered scalars — no extra collective
         counts = jnp.minimum(shares, iocfg.serve_lanes)         # [P]
         my_share = shares[jax.lax.axis_index("pipe")]
-        pqueues, s_de, h_de, f_de, _ = vio.dequeue_device(pqueues, iocfg,
-                                                          my_share)
+        with scope("vio_dequeue"):
+            pqueues, s_de, h_de, f_de, _ = vio.dequeue_device(
+                pqueues, iocfg, my_share)
         # -- route lanes to engines (the one lane-data collective; slot,
         # hash, and features pack into a single [L, 2+K] int32 gather —
         # int32<->uint32 casts round-trip bitwise) -------------------------
-        lane_pack = jnp.concatenate(
-            [s_de[:, None], h_de.astype(I32)[:, None],
-             f_de.reshape(f_de.shape[0], -1)], axis=1)
-        lanes = jax.lax.all_gather(lane_pack, "pipe")       # [P, L, 2+K]
-        intake = vio.engine_intake(freeg, jnp.sum(counts))      # [E]
-        e_idx = jax.lax.axis_index("engine")
-        estart = (jnp.cumsum(intake) - intake)[e_idx]
-        pipe_of, lane_of, valid_in = route_ranks(
-            counts, serve_lanes, estart, intake[e_idx])
-        flat = pipe_of * iocfg.serve_lanes + lane_of
-        sel = lanes.reshape(num_pipes * iocfg.serve_lanes, -1)[flat]
-        eq = vio.enqueue_engine(
-            eq, iocfg, num_pipes, valid_in,
-            sel[:, 0], sel[:, 1].astype(jnp.uint32),
-            sel[:, 2:].reshape(serve_lanes, iocfg.feat_len,
-                               iocfg.feat_dim),
-            pipe_of)
+        with scope("vio_enqueue"):
+            lane_pack = jnp.concatenate(
+                [s_de[:, None], h_de.astype(I32)[:, None],
+                 f_de.reshape(f_de.shape[0], -1)], axis=1)
+            lanes = jax.lax.all_gather(lane_pack, "pipe")   # [P, L, 2+K]
+            intake = vio.engine_intake(freeg, jnp.sum(counts))  # [E]
+            e_idx = jax.lax.axis_index("engine")
+            estart = (jnp.cumsum(intake) - intake)[e_idx]
+            pipe_of, lane_of, valid_in = route_ranks(
+                counts, serve_lanes, estart, intake[e_idx])
+            flat = pipe_of * iocfg.serve_lanes + lane_of
+            sel = lanes.reshape(num_pipes * iocfg.serve_lanes, -1)[flat]
+            eq = vio.enqueue_engine(
+                eq, iocfg, num_pipes, valid_in,
+                sel[:, 0], sel[:, 1].astype(jnp.uint32),
+                sel[:, 2:].reshape(serve_lanes, iocfg.feat_len,
+                                   iocfg.feat_dim),
+                pipe_of)
         # -- per-engine service (varies over "engine" only) -----------------
-        eq, es, eh, ef, ep, srv = vio.dequeue_engine(eq, iocfg, num_pipes,
-                                                     ebudget)
-        ecls = model.infer(ef)
+        with scope("vio_dequeue"):
+            eq, es, eh, ef, ep, srv = vio.dequeue_engine(eq, iocfg,
+                                                         num_pipes, ebudget)
+        with scope("infer"):
+            ecls = model.infer(ef)
         depth_self = eq["tail"] - eq["head"]
         # -- results return through the owning pipe's delay line (the one
         # id+class collective: [slot, hash, class, pipe, count] rows) ------
-        res_pack = jnp.stack([es, eh.astype(I32), ecls, ep,
-                              jnp.full_like(es, srv)])          # [5, S]
-        res = jax.lax.all_gather(res_pack, "engine")        # [E, 5, S]
-        res_s, res_c, res_p = res[:, 0], res[:, 2], res[:, 3]
-        res_h = res[:, 1].astype(jnp.uint32)
-        res_n = res[:, 4, 0]
-        eng_id = jnp.broadcast_to(
-            jnp.arange(num_engines, dtype=I32)[:, None], res_s.shape)
-        (sel_s, sel_h, sel_c, sel_e), my_cnt = gather_results(
-            res_p, res_n, jax.lax.axis_index("pipe"),
-            (res_s, res_h, res_c, eng_id))
-        if masked:
-            # frozen pipes still receive backlog verdicts; stamp them with
-            # the farm-wide clock, not the dummy replay's timestamps
-            push_ts = jnp.where(active, aux["now"], hi) + loop_latency_us
-        else:
-            push_ts = aux["now"] + loop_latency_us
-        pdline = dl.push(pdline, push_ts, sel_s, sel_h, sel_c, my_cnt,
-                         engines=sel_e)
+        with scope("delay_push"):
+            res_pack = jnp.stack([es, eh.astype(I32), ecls, ep,
+                                  jnp.full_like(es, srv)])      # [5, S]
+            res = jax.lax.all_gather(res_pack, "engine")    # [E, 5, S]
+            res_s, res_c, res_p = res[:, 0], res[:, 2], res[:, 3]
+            res_h = res[:, 1].astype(jnp.uint32)
+            res_n = res[:, 4, 0]
+            eng_id = jnp.broadcast_to(
+                jnp.arange(num_engines, dtype=I32)[:, None], res_s.shape)
+            (sel_s, sel_h, sel_c, sel_e), my_cnt = gather_results(
+                res_p, res_n, jax.lax.axis_index("pipe"),
+                (res_s, res_h, res_c, eng_id))
+            if masked:
+                # frozen pipes still receive backlog verdicts; stamp them
+                # with the farm-wide clock, not the dummy replay's
+                # timestamps
+                push_ts = jnp.where(active, aux["now"], hi) \
+                    + loop_latency_us
+            else:
+                push_ts = aux["now"] + loop_latency_us
+            pdline = dl.push(pdline, push_ts, sel_s, sel_h, sel_c, my_cnt,
+                             engines=sel_e)
         # in-scan control plane: rebuild this pipe's LUT + roll its window
         # when the chunk closes a T_w window — no host round trip
-        pstate = jax.lax.cond(
-            cp, lambda s: rl.control_plane_update(s, local_cfg),
-            lambda s: s, pstate)
+        with scope("control_plane"):
+            pstate = jax.lax.cond(
+                cp, lambda s: rl.control_plane_update(s, local_cfg),
+                lambda s: s, pstate)
         pstats = jnp.stack([aux["granted"], aux["classified"],
                             aux["n_tree"]])
         if masked:
@@ -334,17 +343,20 @@ def make_farm_tail(num_pipes: int, num_engines: int, iocfg: vio.IOConfig,
         state, queues, dline, aux = de_local(state, queues, dline, chunk)
         ebudget = vio.step_budget(aux["ts_first"], aux["now"], tail_rate,
                                   iocfg.queue_len)
-        queues, s2, h2, f2, cnt = vio.dequeue_device(
-            queues, iocfg, num_engines * ebudget)
-        assign = vio.engine_intake(
-            jnp.full((num_engines,), ebudget, I32), cnt)
-        tags = jnp.searchsorted(jnp.cumsum(assign),
-                                jnp.arange(s2.shape[0], dtype=I32),
-                                side="right").astype(I32)
-        tags = jnp.minimum(tags, num_engines - 1)
-        cls = model.infer(f2)
-        dline = dl.push(dline, aux["now"] + loop_latency_us, s2, h2, cls,
-                        cnt, engines=tags)
+        with scope("vio_dequeue"):
+            queues, s2, h2, f2, cnt = vio.dequeue_device(
+                queues, iocfg, num_engines * ebudget)
+            assign = vio.engine_intake(
+                jnp.full((num_engines,), ebudget, I32), cnt)
+            tags = jnp.searchsorted(jnp.cumsum(assign),
+                                    jnp.arange(s2.shape[0], dtype=I32),
+                                    side="right").astype(I32)
+            tags = jnp.minimum(tags, num_engines - 1)
+        with scope("infer"):
+            cls = model.infer(f2)
+        with scope("delay_push"):
+            dline = dl.push(dline, aux["now"] + loop_latency_us, s2, h2,
+                            cls, cnt, engines=tags)
         stats = jnp.stack([aux["granted"], cnt, aux["classified"],
                            aux["n_tree"]])
         return (state, queues, dline), (aux["verdict"], stats, assign)

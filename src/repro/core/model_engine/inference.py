@@ -140,18 +140,3 @@ class CycleModel:
         macs = macs_per_inference(cfg)
         issue_us = macs / (self.array_width ** 2) / self.f_clk_hz * 1e6
         return self.latency_us(cfg) + (per_engine - 1) * issue_us
-
-
-def tpu_latency_us(cfg: TrafficModelConfig, batch: int = 128) -> Dict:
-    """Roofline latency of the same window batch on one TPU v5e MXU.
-
-    compute = MACs*2 / 197 TFLOP/s (int8 runs at >= bf16 peak); memory =
-    weight+activation bytes / 819 GB/s.  Reported in the Fig. 11 analogue.
-    """
-    macs = macs_per_inference(cfg) * batch
-    flops = 2.0 * macs
-    w_bytes = macs_per_inference(cfg)  # int8: ~1 byte per unique MAC weight
-    t_compute = flops / 197e12 * 1e6
-    t_memory = (w_bytes + batch * cfg.seq_len * 2 * 4) / 819e9 * 1e6
-    return {"compute_us": t_compute, "memory_us": t_memory,
-            "latency_us": max(t_compute, t_memory)}

@@ -9,7 +9,7 @@ chip is attached — so a kernel Mosaic refuses fails here, at no chip time:
 * the int8 GEMM at every layer shape the paper CNN (``fenix_cnn``) issues
   at the default 1024 service lanes;
 * one jitted scan of the single-pipe device driver with both compiled
-  backends, serving the paper CNN.
+  backends, serving the paper CNN, whose ops carry every stage scope.
 
 Nothing runs, so results are not checked here: the kernels' bit-for-bit
 parity is the interpret-mode suites' job, and on the chip ``chip_smoke.py``
@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import tracing
 from repro.configs.fenix_models import fenix_cnn
 from repro.core.data_engine.state import EngineConfig
 from repro.core.fenix import PKT_KEYS, FenixConfig, FenixSystem
@@ -136,31 +137,50 @@ def test_int8_gemm_compiles_at_paper_cnn_shapes(one_chip, monkeypatch):
             (m, k, n)
 
 
-def test_single_pipe_scan_compiles_with_compiled_kernels(one_chip,
-                                                         monkeypatch):
+@pytest.fixture(scope="module")
+def scan_text(one_chip):
     """One jitted device-driver scan (2 steps of BATCH packets) serving
-    the paper CNN, with both kernels on their compiled backends."""
-    monkeypatch.setattr(kernel_backend, "on_tpu", lambda: True)
-    mcfg = fenix_cnn()
-    calib = np.full((8, mcfg.seq_len, 2), 100, np.int32)
-    qp = quantize_traffic(traffic.init(mcfg, seed=0), mcfg,
-                          jnp.asarray(calib))
-    cfg = FenixConfig(driver="device", batch_size=BATCH,
-                      engine=EngineConfig(gate_backend="pallas_tpu"),
-                      matmul_backend="pallas_tpu")
-    sys_ = FenixSystem(cfg, EngineModel(mcfg, qp))
-    sys_._ensure_jits()
+    the paper CNN, with both kernels on their compiled backends, as the
+    compiled module's text."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kernel_backend, "on_tpu", lambda: True)
+        mcfg = fenix_cnn()
+        calib = np.full((8, mcfg.seq_len, 2), 100, np.int32)
+        qp = quantize_traffic(traffic.init(mcfg, seed=0), mcfg,
+                              jnp.asarray(calib))
+        cfg = FenixConfig(driver="device", batch_size=BATCH,
+                          engine=EngineConfig(gate_backend="pallas_tpu"),
+                          matmul_backend="pallas_tpu")
+        sys_ = FenixSystem(cfg, EngineModel(mcfg, qp))
+        sys_._ensure_jits()
 
-    def on_chip(x):
-        return _spec(one_chip, x.shape, x.dtype)
+        def on_chip(x):
+            return _spec(one_chip, x.shape, x.dtype)
 
-    carry = jax.tree.map(on_chip, (sys_.state, sys_.queues, sys_._dl))
-    dtypes = {"src_ip": jnp.uint32, "dst_ip": jnp.uint32,
-              "src_port": jnp.uint32, "dst_port": jnp.uint32,
-              "proto": jnp.uint32, "ts_us": I32, "pkt_len": I32}
-    assert set(dtypes) == set(PKT_KEYS)
-    chunks = {k: _spec(one_chip, (2, BATCH), dt) for k, dt in dtypes.items()}
-    chunks["_cp"] = _spec(one_chip, (2,), jnp.bool_)
-    text = sys_._scan_jit.lower(carry, chunks).compile().as_text()
+        carry = jax.tree.map(on_chip, (sys_.state, sys_.queues, sys_._dl))
+        dtypes = {"src_ip": jnp.uint32, "dst_ip": jnp.uint32,
+                  "src_port": jnp.uint32, "dst_port": jnp.uint32,
+                  "proto": jnp.uint32, "ts_us": I32, "pkt_len": I32}
+        assert set(dtypes) == set(PKT_KEYS)
+        chunks = {k: _spec(one_chip, (2, BATCH), dt)
+                  for k, dt in dtypes.items()}
+        chunks["_cp"] = _spec(one_chip, (2,), jnp.bool_)
+        return sys_._scan_jit.lower(carry, chunks).compile().as_text()
+
+
+def test_single_pipe_scan_compiles_with_compiled_kernels(scan_text):
     # the fused gate plus one GEMM per CNN layer
-    assert text.count("tpu_custom_call") >= 7
+    assert scan_text.count("tpu_custom_call") >= 7
+
+
+def test_single_pipe_scan_names_every_stage(scan_text):
+    """The compiled scan carries all ten stage scopes, and the stage map
+    puts each compiled kernel in its stage."""
+    stage_of = tracing.stages_in_hlo(scan_text)
+    assert set(tracing.STAGES) <= set(stage_of.values())
+    kernels = {n: s for n, s in stage_of.items()
+               if n.split(".")[0] in ("fenix_fused_gate", "fenix_int8_gemm")}
+    assert {s for n, s in kernels.items()
+            if n.startswith("fenix_fused_gate")} == {"gate"}
+    gemms = [s for n, s in kernels.items() if n.startswith("fenix_int8_gemm")]
+    assert len(gemms) >= 6 and set(gemms) == {"infer"}
