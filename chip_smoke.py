@@ -13,7 +13,9 @@ One chip, three phases:
    equals ``fused_admission_ref`` bit for bit at batch 4096 (and across
    a 3-tile grid); the on-core PRNG variant grants within 0.05 of the
    LUT's expected fraction; the compiled int8 GEMM equals
-   ``int8_matmul_ref`` at every layer of the paper CNN over 1024 lanes.
+   ``int8_matmul_ref`` at every layer of the paper CNN over 1024 lanes;
+   the paper RNN's logits over 1024 lanes equal a numpy forward that
+   indexes its tanh table.
 3. main path — ``FenixSystem(FenixConfig(model="int8_cnn",
    driver="device", batch_size=4096)).run_trace(TraceSpec(capture))``
    on a capture synthesized from a seed, with the platform's backends:
@@ -153,11 +155,12 @@ def gate_parity() -> None:
 def gemm_parity() -> None:
     import numpy as np
     import jax.numpy as jnp
-    from repro.configs.fenix_models import fenix_cnn
+    from repro.configs.fenix_models import fenix_cnn, fenix_rnn
     from repro.kernels.int8_matmul import ops
     from repro.kernels.int8_matmul.ref import int8_matmul_ref
     from repro.models import traffic
     from repro.quant.quantize import int8_apply, quantize_traffic
+    from repro.quant.ref import int8_rnn_ref
 
     mcfg = fenix_cnn()
     rng = np.random.default_rng(SEED)
@@ -194,6 +197,15 @@ def gemm_parity() -> None:
                         backend="pallas_tpu")
     assert bool(jnp.all(logits == logits_ref))
     log("int8 paper CNN logits over 1024 lanes: bit-identical to ref")
+    rcfg = fenix_rnn()
+    rqp = quantize_traffic(traffic.init(rcfg, seed=SEED), rcfg,
+                           jnp.asarray(payload[:256]))
+    got = np.asarray(int8_apply(rqp, rcfg, jnp.asarray(payload),
+                                backend="pallas_tpu"))
+    want, _ = int8_rnn_ref(rqp, rcfg, payload)
+    assert (got == want).all(), int((got != want).sum())
+    log("int8 paper RNN logits over 1024 lanes: bit-identical to the "
+        "numpy forward that indexes the tanh table")
 
 
 # -- phase 3: the main path ---------------------------------------------------

@@ -9,12 +9,13 @@ a single right-shift — no multipliers, exactly what the FPGA (and the
 Pallas int8 kernel) executes.
 
 Nonlinearities: relu is a clip; tanh (RNN cell) is a 512-entry int8 LUT
-indexed by the pre-activation's high bits — the standard FPGA mapping.
+indexed by the pre-activation's high bits — the standard FPGA mapping —
+read on the device as a compare-and-select staircase (``lut_lookup``).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,36 @@ def dequantize_array(x_q: np.ndarray, shift: int) -> np.ndarray:
     grid step; values beyond the representable range saturate.
     """
     return np.asarray(x_q, np.float64) * (2.0 ** -shift)
+
+
+def tanh_table(sh: int) -> np.ndarray:
+    """The RNN cell's 512-entry int8 tanh table on the 2^-sh output grid:
+    entry ``i`` is ``tanh((i - 256) * 2^-4)``, rounded and saturated."""
+    return _q(np.tanh(np.arange(-256, 256) / (1 << 4)), sh)
+
+
+def lut_lookup(lut: jax.Array) -> Callable[[jax.Array], jax.Array]:
+    """``idx -> lut[idx]`` for integer indices in ``[0, len(lut))``, bit
+    for bit, without a gather.
+
+    The table is read as a staircase: start from ``lut[0]`` and, in
+    ascending order of each index ``k`` where the entry differs from the
+    one before, take ``lut[k]`` wherever ``idx >= k``.  The table is
+    constant between those indices, so the last ``k <= idx`` gives
+    ``lut[idx]``, whatever the table's shape.  That is one compare and one
+    select per change, element-wise on the vector unit (60 for
+    ``tanh_table(6)``), where a gather on the TPU costs far more.  ``lut``
+    must be concrete, as are the shifts ``int8_apply`` reads.
+    """
+    t = np.asarray(lut).astype(np.int64)
+    steps = [int(k) for k in np.flatnonzero(np.diff(t)) + 1]
+
+    def lookup(idx: jax.Array) -> jax.Array:
+        out = jnp.full(idx.shape, int(t[0]), I32)
+        for k in steps:
+            out = jnp.where(idx >= k, int(t[k]), out)
+        return out.astype(lut.dtype)
+    return lookup
 
 
 def _collect_activations(params: Dict, cfg: TrafficModelConfig,
@@ -147,9 +178,7 @@ def quantize_traffic(params: Dict, cfg: TrafficModelConfig,
         qp["cell/shift_h"] = sh + swh - sa_pre
         assert qp["cell/shift_x"] >= 0 and qp["cell/shift_h"] >= 0
         # tanh LUT: index = clip(pre_q >> (sa_pre-4), -256, 255)
-        idx = np.arange(-256, 256)
-        lut_in = idx / (1 << 4)                      # pre at scale 2^-4
-        qp["tanh_lut"] = _q(np.tanh(lut_in), sh)
+        qp["tanh_lut"] = tanh_table(sh)
         qp["cell/lut_preshift"] = sa_pre - 4
         qlayer("head", params["head/w"], params["head/b"], sh,
                max(sa["head"], 0))
@@ -188,6 +217,8 @@ def int8_apply(qp: Dict, cfg: TrafficModelConfig, payload: jax.Array,
         return int8_matmul(x, qp["head/w"], qp["head/b"], None,
                            backend=backend)
     # rnn
+    tanh = lut_lookup(qp["tanh_lut"])
+
     def cell(h, xt):
         accx = int8_matmul(xt, qp["cell/wx"], qp["cell/b"], None,
                            backend=backend)
@@ -197,7 +228,7 @@ def int8_apply(qp: Dict, cfg: TrafficModelConfig, payload: jax.Array,
         pre = (accx >> sx if sx > 0 else accx) \
             + (acch >> sh_ if sh_ > 0 else acch)      # on the cell_pre grid
         lidx = jnp.clip(pre >> int(qp["cell/lut_preshift"]), -256, 255)
-        h2 = qp["tanh_lut"][lidx + 256]
+        h2 = tanh(lidx + 256)
         return h2, None
 
     h0 = jnp.zeros((b, cfg.rnn_units), I8)

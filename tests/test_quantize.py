@@ -6,11 +6,14 @@
   public dispatch surface (``backend=`` names and the platform default),
 * a trained-and-quantized model stays within a fixed accuracy delta of
   its float parent on the synthetic fixture corpus, and the quantized
-  checkpoint round-trips through ``save_quantized``/``load_quantized``.
+  checkpoint round-trips through ``save_quantized``/``load_quantized``,
+* the RNN cell's table lookup returns ``T[i]`` for every index without a
+  gather, and the integer RNN equals a numpy forward that indexes it.
 """
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,8 +22,11 @@ from hypothesis import given, settings, strategies as st
 from repro.core.model_engine import serving
 from repro.kernels.int8_matmul import ops
 from repro.models import traffic
-from repro.quant.quantize import (dequantize_array, int8_apply,
-                                  quantize_array)
+from repro.configs.fenix_models import fenix_rnn_tiny
+from repro.quant.quantize import (dequantize_array, int8_apply, lut_lookup,
+                                  quantize_array, quantize_traffic,
+                                  tanh_table)
+from repro.quant.ref import int8_rnn_ref
 
 # ---------------------------------------------------------------------------
 # quantize -> dequantize round trip
@@ -189,3 +195,73 @@ def test_fenix_config_backend_overrides_model_object(trained):
     assert dataclasses.replace(sys_.model, backend="ref") == model
     with pytest.raises(ValueError, match="EngineModel"):
         FenixSystem(FenixConfig(matmul_backend="pallas"), ByLenModel())
+
+
+# ---------------------------------------------------------------------------
+# the RNN cell's tanh table lookup
+# ---------------------------------------------------------------------------
+
+IDX = jnp.arange(512, dtype=jnp.int32)
+
+
+def _lookup(table: np.ndarray):
+    """(every entry looked up, the lookup's jaxpr as text)."""
+    fn = lut_lookup(jnp.asarray(table, jnp.int8))
+    return np.asarray(fn(IDX)), str(jax.make_jaxpr(fn)(IDX))
+
+
+@pytest.mark.parametrize("sh", range(3, 13))
+def test_lut_lookup_exact_on_tanh_tables(sh):
+    """The table ``quantize_traffic`` builds at output shift ``sh`` (6-12
+    are those a calibration can give, |tanh| <= 1) reads back entry for
+    entry, without a gather."""
+    table = tanh_table(sh)
+    got, jaxpr = _lookup(table)
+    assert got.dtype == np.int8
+    np.testing.assert_array_equal(got, table)
+    assert "gather" not in jaxpr
+
+
+def _tables():
+    rng = np.random.default_rng(14)
+    yield "flat", np.full(512, 3)
+    yield "flat_at_-127", np.full(512, -127)
+    yield "one_step_full_range", np.repeat([-127, 127], [300, 212])
+    yield "every_value", np.linspace(-127, 127, 512).round()
+    for seed in range(4):
+        t = np.sort(rng.integers(-127, 128, 512))
+        if seed % 2:                      # reach both ends
+            t[0], t[-1] = -127, 127
+        yield f"random_{seed}", t
+    yield "sparse_steps", np.sort(rng.choice([-127, -3, 0, 1, 90, 127], 512))
+    dip = tanh_table(6)
+    dip[300] = dip[299] - 1
+    yield "one_dip", dip
+    yield "shuffled", np.random.default_rng(3).permutation(tanh_table(6))
+
+
+@pytest.mark.parametrize("table", [t for _, t in _tables()],
+                         ids=[n for n, _ in _tables()])
+def test_lut_lookup_exact_on_any_table(table):
+    """Flat, full-range, random non-decreasing, and stepping-down tables
+    all read back entry for entry without a gather."""
+    got, jaxpr = _lookup(table)
+    np.testing.assert_array_equal(got, table)
+    assert "gather" not in jaxpr
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_int8_rnn_tiny_logits_equal_numpy_table_forward(backend):
+    cfg = fenix_rnn_tiny()
+    rng = np.random.default_rng(14)
+    payload = np.stack([rng.integers(40, 1500, (256, cfg.seq_len)),
+                        rng.integers(0, 100_000, (256, cfg.seq_len))],
+                       axis=-1).astype(np.int32)
+    qp = quantize_traffic(traffic.init(cfg, seed=14), cfg,
+                          jnp.asarray(payload[:64]))
+    assert int(qp["cell/lut_preshift"]) >= 0
+    want, h = int8_rnn_ref(qp, cfg, payload)
+    assert len(np.unique(h)) > 20       # many table entries were read
+    got = np.asarray(int8_apply(qp, cfg, jnp.asarray(payload),
+                                backend=backend))
+    np.testing.assert_array_equal(got, want)

@@ -9,7 +9,9 @@ chip is attached — so a kernel Mosaic refuses fails here, at no chip time:
 * the int8 GEMM at every layer shape the paper CNN (``fenix_cnn``) issues
   at the default 1024 service lanes;
 * one jitted scan of the single-pipe device driver with both compiled
-  backends, serving the paper CNN, whose ops carry every stage scope.
+  backends, serving the paper CNN, whose ops carry every stage scope;
+* the paper RNN (``fenix_rnn``) over 1024 lanes, whose cell loop holds
+  its two GEMMs and no gather (the tanh table is read element-wise).
 
 Nothing runs, so results are not checked here: the kernels' bit-for-bit
 parity is the interpret-mode suites' job, and on the chip ``chip_smoke.py``
@@ -19,6 +21,8 @@ around the compiles (a described chip's executables cannot be read back).
 """
 
 import os
+import re
+from typing import Dict, Set
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +31,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import tracing
-from repro.configs.fenix_models import fenix_cnn
+from repro.configs.fenix_models import fenix_cnn, fenix_rnn
 from repro.core.data_engine.state import EngineConfig
 from repro.core.fenix import PKT_KEYS, FenixConfig, FenixSystem
 from repro.core.model_engine.inference import EngineModel
@@ -184,3 +188,52 @@ def test_single_pipe_scan_names_every_stage(scan_text):
             if n.startswith("fenix_fused_gate")} == {"gate"}
     gemms = [s for n, s in kernels.items() if n.startswith("fenix_int8_gemm")]
     assert len(gemms) >= 6 and set(gemms) == {"infer"}
+
+
+def _computations(text: str) -> Dict[str, str]:
+    """HLO module text -> {computation name: its body}."""
+    comps, name, lines = {}, None, []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if name is None and head:
+            name, lines = head.group(1), []
+        elif name is not None and line.strip() == "}":
+            comps[name] = "\n".join(lines)
+            name = None
+        elif name is not None:
+            lines.append(line)
+    return comps
+
+
+def _reachable(comps: Dict[str, str], roots) -> Set[str]:
+    """The computations ``roots`` call, directly or through others."""
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        todo += [r for r in re.findall(r"%([\w.\-]+)", comps[c])
+                 if r in comps]
+    return seen
+
+
+def test_rnn_cell_loop_has_no_gather(one_chip, monkeypatch):
+    """The paper RNN over LANES lanes with the compiled GEMM: the cell's
+    while body (and every fusion it calls) holds the two cell GEMMs and
+    no gather; the embedding takes before the loop may gather."""
+    monkeypatch.setattr(kernel_backend, "on_tpu", lambda: True)
+    mcfg = fenix_rnn()
+    rng = np.random.default_rng(0)
+    calib = rng.integers(1, 1500, (64, mcfg.seq_len, 2)).astype(np.int32)
+    qp = quantize_traffic(traffic.init(mcfg, seed=0), mcfg,
+                          jnp.asarray(calib))
+    text = _compiled_text(
+        lambda p: int8_apply(qp, mcfg, p, backend="pallas_tpu"),
+        _spec(one_chip, (LANES, mcfg.seq_len, 2)))
+    comps = _computations(text)
+    bodies = re.findall(r"\bbody=%?([\w.\-]+)", text)
+    assert len(bodies) == 1, bodies
+    inside = [comps[c] for c in _reachable(comps, bodies)]
+    assert sum(b.count("tpu_custom_call") for b in inside) == 2
+    assert not any(re.search(r"\sgather\(", b) for b in inside)
