@@ -31,7 +31,7 @@ import sys
 import jax.numpy as jnp
 import numpy as np
 
-from fenixbench import model as ref_model
+from fenixbench import models
 from fenixbench import run
 
 
@@ -44,8 +44,8 @@ class ControlModel:
         self.num_classes = mcfg["num_classes"]
 
     def _host(self, payload):
-        logits = ref_model.classify(self.qp, self.mcfg,
-                                    np.asarray(payload), bits=4)
+        logits = models.classify(self.qp, self.mcfg, np.asarray(payload),
+                                 bits=4)
         return logits.argmax(axis=1).astype(np.int32)
 
     def infer(self, payload):

@@ -39,7 +39,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from fenixbench import model as ref_model
+from fenixbench import models
 
 MAX_ALTERNATIVES = 256
 # verdict changes judged per run at most, drawn from the seed
@@ -228,20 +228,20 @@ def judge(slots: List[Dict[str, np.ndarray]], drains: np.ndarray,
     gap = UNEXPLAINED if n_unexplained else 0
     if windows:
         pay = np.asarray(windows, np.int64)
-        logits = ref_model.classify(qp, mcfg, pay)
+        logits = models.classify(qp, mcfg, pay)
         best = logits.max(axis=1)
         cls = logits.argmax(axis=1)
         for c, ids in judged:
             ids = np.asarray(ids)
-            gap = max(gap, int((best[ids] - logits[ids, c]).min()))
+            gap = max(gap, (best[ids] - logits[ids, c]).min().item())
         missed = sum(v not in set(cls[np.asarray(ids)].tolist())
                      for v, ids in checked)
         out["due_missed_pct"] = 100.0 * missed / max(len(checked), 1)
         if control and judged:
             ids = np.concatenate([np.asarray(i) for _, i in judged])
-            low = ref_model.classify(qp, mcfg, pay[ids], bits=4)
-            out["control_gap"] = int((best[ids] - logits[ids, low.argmax(
-                axis=1)]).max())
+            low = models.classify(qp, mcfg, pay[ids], bits=4)
+            out["control_gap"] = (best[ids] - logits[ids, low.argmax(
+                axis=1)]).max().item()
     out["verdict_gap"] = gap
     out.setdefault("due_missed_pct", 0.0)
     return out

@@ -44,4 +44,4 @@ def test_control_and_faults_are_not_correct(cell, kind, tmp_path):
         m = _measure(cell, tmp_path)
     assert m["result"]["correct"] is False
     name = CAUGHT_BY[kind]
-    assert m["check"][name] > run.LIMITS[name]
+    assert m["check"][name] > m["result"]["check"][name]["limit"]

@@ -15,8 +15,10 @@ CELLS = sorted(w["name"] for w in run.load_cell("cnn_iscx")[0]
                ["workloads"])
 KEYS = {"correct", "attempted", "failed", "metrics", "device", "check"}
 # the kernels' names exist only in the compiled TPU kernels, so their
-# rooflines have nothing to read on the CPU
-TPU_ONLY = {"gate_roofline", "gemm_roofline"}
+# rooflines have nothing to read on the CPU; XLA:CPU runs most of the
+# model's ops on intra-op threads that the trace selector leaves out, so
+# the Model Engine's stage time reads nothing there in most runs
+TPU_ONLY = {"gate_roofline", "gemm_roofline", "inference_us"}
 
 
 def cpu_lines(plane, lines):

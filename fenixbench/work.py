@@ -1,5 +1,6 @@
 """Operations and bytes that the benchmark's rooflines count, computed
-from a configuration's own unpadded shapes, and the table of peaks.
+by each model kind's module from a configuration's own unpadded shapes,
+and the table of peaks.
 
 These are the yardstick: kept with the benchmark so that a change to the
 program cannot move them.
@@ -10,6 +11,8 @@ from __future__ import annotations
 import json
 import os
 from typing import Dict, List, Tuple
+
+from fenixbench import models
 
 PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "peaks.json")
@@ -31,34 +34,19 @@ def peaks(device_kind: str) -> Dict[str, float]:
 
 
 def gemms(mcfg: Dict) -> List[Tuple[int, int, int, int]]:
-    """The model's GEMM calls per inference: (rows, K, N, out_bytes),
-    rows being the GEMM rows one inference contributes; each listed call
-    runs ``repeat`` times where a layer repeats (the RNN's timesteps are
-    listed one by one)."""
-    d_in = 2 * mcfg["embed_dim"]
-    t = mcfg["seq_len"]
-    out = []
-    if mcfg["kind"] == "cnn":
-        c_prev = d_in
-        for ch in mcfg["conv_filters"]:
-            out.append((t, mcfg["conv_kernel"] * c_prev, ch, 1))
-            c_prev = ch
-        for fc in mcfg["fc_dims"]:
-            out.append((1, c_prev, fc, 1))
-            c_prev = fc
-        out.append((1, c_prev, mcfg["num_classes"], 4))
-    else:
-        u = mcfg["rnn_units"]
-        for _ in range(t):
-            out.append((1, d_in, u, 4))
-            out.append((1, u, u, 4))
-        out.append((1, u, mcfg["num_classes"], 4))
-    return out
+    """The model's ``fenix_int8_gemm`` calls per inference, as its kind
+    module counts them: (rows, K, N, out_bytes)."""
+    return models.get(mcfg["kind"]).gemms(mcfg)
+
+
+def gemm_macs_per_inference(mcfg: Dict) -> int:
+    """Multiply-accumulates of one feature window inside the GEMMs."""
+    return sum(r * k * n for r, k, n, _ in gemms(mcfg))
 
 
 def macs_per_inference(mcfg: Dict) -> int:
-    """Multiply-accumulates of one feature window."""
-    return sum(r * k * n for r, k, n, _ in gemms(mcfg))
+    """Multiply-accumulates of one feature window, the whole model."""
+    return models.get(mcfg["kind"]).macs_per_inference(mcfg)
 
 
 def gemm_weight_bytes(mcfg: Dict) -> int:
